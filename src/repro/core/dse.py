@@ -89,7 +89,7 @@ from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
-from . import faultinject
+from . import faultinject, tracing
 from .backward import expand_training_graph
 from .conv_model import (conv_dram_bits, conv_multipliers,
                          conv_quantities_batch, conv_segment_quantities,
@@ -452,7 +452,8 @@ def get_conv_table(hw: HardwareSpec, layers: Sequence[ConvLayer]) -> ConvTable:
                 _CONV_TABLE_CACHE[key] = t
                 return t
         _TABLE_CACHE_STATS["conv_builds"] += 1
-        t = _CONV_TABLE_CACHE[key] = ConvTable(hw, layers)
+        with tracing.span("repro.dse.table_build", n=1):
+            t = _CONV_TABLE_CACHE[key] = ConvTable(hw, layers)
         if store is not None:
             store.save("conv", key, t)
         return t
@@ -476,7 +477,8 @@ def get_simd_table(hw: HardwareSpec, layers: Sequence[SimdLayer]) -> SimdTable:
                 _SIMD_TABLE_CACHE[key] = t
                 return t
         _TABLE_CACHE_STATS["simd_builds"] += 1
-        t = _SIMD_TABLE_CACHE[key] = SimdTable(hw, layers)
+        with tracing.span("repro.dse.table_build", n=1):
+            t = _SIMD_TABLE_CACHE[key] = SimdTable(hw, layers)
         if store is not None:
             store.save("simd", key, t)
         return t
@@ -505,7 +507,8 @@ def get_gemm_table(hw: HardwareSpec, layers: Sequence[GemmLayer]) -> GemmTable:
                 _GEMM_TABLE_CACHE[key] = t
                 return t
         _TABLE_CACHE_STATS["gemm_builds"] += 1
-        t = _GEMM_TABLE_CACHE[key] = GemmTable(hw, layers)
+        with tracing.span("repro.dse.table_build", n=1):
+            t = _GEMM_TABLE_CACHE[key] = GemmTable(hw, layers)
         if store is not None:
             store.save("gemm", key, t)
         return t
@@ -578,43 +581,45 @@ def _batch_build_conv_tables_locked(hws: Sequence[HardwareSpec],  # holds-lock: 
         missing = still
     if not missing:
         return
-    base = missing[0][1]
-    tail = _conv_hw_key(base)[3:]       # bbuf, bit widths, J, K
-    if any(key[0][3:] != tail for key, _ in missing):
-        raise ValueError("batch_build_conv_tables requires all hardware "
-                         "variants to share every conv invariant except "
-                         "the wbuf/ibuf/obuf sizes")
-    triples = [(hw.wbuf, hw.ibuf, hw.obuf) for _, hw in missing]
-    n_l, n_t = len(layers), len(triples)
-    f_fields = ("c_tile", "o1", "o2", "o4", "o5", "w_bits", "wb_bits",
-                "i_bits", "ps_bits", "pls_bits")
-    mats = {f: np.zeros((n_l, n_t)) for f in f_fields}
-    busy = np.zeros((n_l, n_t), dtype=np.int64)
-    dram = np.zeros((n_l, n_t), dtype=np.int64)
-    sram = {buf: np.zeros((n_l, n_t), dtype=np.int64)
-            for buf in ("wbuf", "ibuf", "obuf", "bbuf")}
-    for x, layer in enumerate(layers):
-        q = conv_quantities_batch(
-            base, layer, _derive_conv_tiling_arrays(base, triples, layer))
-        for f in f_fields:
-            mats[f][x] = q[f]
-        busy[x] = q["busy"]
-        dram[x] = q["dram"]
-        for buf in sram:
-            sram[buf][x] = q["sram"][buf]
-    phases = tuple(l.phase for l in layers)
-    # column views into the [n_layers x n_triples] matrices (a few KB per
-    # matrix — cheaper than 14 copies per table, and numerically identical)
-    for i, (key, _hw) in enumerate(missing):
-        t = _CONV_TABLE_CACHE[key] = ConvTable._from_columns(
-            phases, {f: mats[f][:, i] for f in f_fields},
-            busy[:, i], dram[:, i],
-            {buf: sram[buf][:, i] for buf in sram})
-        _PREFETCHED_UNTOUCHED.add(key)
-        _TABLE_CACHE_STATS["conv_batch_builds"] += 1
-        _TABLE_CACHE_STATS["conv_builds"] += 1
-        if store is not None:
-            store.save("conv", key, t)
+    with tracing.span("repro.dse.table_build", n=len(missing)):
+        base = missing[0][1]
+        tail = _conv_hw_key(base)[3:]       # bbuf, bit widths, J, K
+        if any(key[0][3:] != tail for key, _ in missing):
+            raise ValueError("batch_build_conv_tables requires all hardware "
+                             "variants to share every conv invariant except "
+                             "the wbuf/ibuf/obuf sizes")
+        triples = [(hw.wbuf, hw.ibuf, hw.obuf) for _, hw in missing]
+        n_l, n_t = len(layers), len(triples)
+        f_fields = ("c_tile", "o1", "o2", "o4", "o5", "w_bits", "wb_bits",
+                    "i_bits", "ps_bits", "pls_bits")
+        mats = {f: np.zeros((n_l, n_t)) for f in f_fields}
+        busy = np.zeros((n_l, n_t), dtype=np.int64)
+        dram = np.zeros((n_l, n_t), dtype=np.int64)
+        sram = {buf: np.zeros((n_l, n_t), dtype=np.int64)
+                for buf in ("wbuf", "ibuf", "obuf", "bbuf")}
+        for x, layer in enumerate(layers):
+            q = conv_quantities_batch(
+                base, layer, _derive_conv_tiling_arrays(base, triples, layer))
+            for f in f_fields:
+                mats[f][x] = q[f]
+            busy[x] = q["busy"]
+            dram[x] = q["dram"]
+            for buf in sram:
+                sram[buf][x] = q["sram"][buf]
+        phases = tuple(l.phase for l in layers)
+        # column views into the [n_layers x n_triples] matrices (a few KB
+        # per matrix — cheaper than 14 copies per table, and numerically
+        # identical)
+        for i, (key, _hw) in enumerate(missing):
+            t = _CONV_TABLE_CACHE[key] = ConvTable._from_columns(
+                phases, {f: mats[f][:, i] for f in f_fields},
+                busy[:, i], dram[:, i],
+                {buf: sram[buf][:, i] for buf in sram})
+            _PREFETCHED_UNTOUCHED.add(key)
+            _TABLE_CACHE_STATS["conv_batch_builds"] += 1
+            _TABLE_CACHE_STATS["conv_builds"] += 1
+            if store is not None:
+                store.save("conv", key, t)
 
 
 def batch_build_gemm_tables(hws: Sequence[HardwareSpec],
@@ -651,41 +656,42 @@ def _batch_build_gemm_tables_locked(hws: Sequence[HardwareSpec],  # holds-lock: 
         missing = still
     if not missing:
         return
-    base = missing[0][1]
-    tail = _conv_hw_key(base)[3:]       # bbuf, bit widths, J, K
-    if any(key[0][3:] != tail for key, _ in missing):
-        raise ValueError("batch_build_gemm_tables requires all hardware "
-                         "variants to share every invariant except the "
-                         "wbuf/ibuf/obuf sizes")
-    triples = [(hw.wbuf, hw.ibuf, hw.obuf) for _, hw in missing]
-    n_l, n_t = len(layers), len(triples)
-    f_fields = ("c_tile", "o1", "o2", "o4", "o5", "w_bits", "wb_bits",
-                "i_bits", "ps_bits", "pls_bits")
-    mats = {f: np.zeros((n_l, n_t)) for f in f_fields}
-    busy = np.zeros((n_l, n_t), dtype=np.int64)
-    dram = np.zeros((n_l, n_t), dtype=np.int64)
-    sram = {buf: np.zeros((n_l, n_t), dtype=np.int64)
-            for buf in ("wbuf", "ibuf", "obuf", "bbuf")}
-    for x, layer in enumerate(layers):
-        q = gemm_quantities_batch(
-            base, layer, _derive_gemm_tiling_arrays(base, triples, layer))
-        for f in f_fields:
-            mats[f][x] = q[f]
-        busy[x] = q["busy"]
-        dram[x] = q["dram"]
-        for buf in sram:
-            sram[buf][x] = q["sram"][buf]
-    phases = tuple(l.phase for l in layers)
-    for i, (key, _hw) in enumerate(missing):
-        t = _GEMM_TABLE_CACHE[key] = GemmTable._from_columns(
-            phases, {f: mats[f][:, i] for f in f_fields},
-            busy[:, i], dram[:, i],
-            {buf: sram[buf][:, i] for buf in sram})
-        _PREFETCHED_UNTOUCHED.add(key)
-        _TABLE_CACHE_STATS["gemm_batch_builds"] += 1
-        _TABLE_CACHE_STATS["gemm_builds"] += 1
-        if store is not None:
-            store.save("gemm", key, t)
+    with tracing.span("repro.dse.table_build", n=len(missing)):
+        base = missing[0][1]
+        tail = _conv_hw_key(base)[3:]       # bbuf, bit widths, J, K
+        if any(key[0][3:] != tail for key, _ in missing):
+            raise ValueError("batch_build_gemm_tables requires all hardware "
+                             "variants to share every invariant except the "
+                             "wbuf/ibuf/obuf sizes")
+        triples = [(hw.wbuf, hw.ibuf, hw.obuf) for _, hw in missing]
+        n_l, n_t = len(layers), len(triples)
+        f_fields = ("c_tile", "o1", "o2", "o4", "o5", "w_bits", "wb_bits",
+                    "i_bits", "ps_bits", "pls_bits")
+        mats = {f: np.zeros((n_l, n_t)) for f in f_fields}
+        busy = np.zeros((n_l, n_t), dtype=np.int64)
+        dram = np.zeros((n_l, n_t), dtype=np.int64)
+        sram = {buf: np.zeros((n_l, n_t), dtype=np.int64)
+                for buf in ("wbuf", "ibuf", "obuf", "bbuf")}
+        for x, layer in enumerate(layers):
+            q = gemm_quantities_batch(
+                base, layer, _derive_gemm_tiling_arrays(base, triples, layer))
+            for f in f_fields:
+                mats[f][x] = q[f]
+            busy[x] = q["busy"]
+            dram[x] = q["dram"]
+            for buf in sram:
+                sram[buf][x] = q["sram"][buf]
+        phases = tuple(l.phase for l in layers)
+        for i, (key, _hw) in enumerate(missing):
+            t = _GEMM_TABLE_CACHE[key] = GemmTable._from_columns(
+                phases, {f: mats[f][:, i] for f in f_fields},
+                busy[:, i], dram[:, i],
+                {buf: sram[buf][:, i] for buf in sram})
+            _PREFETCHED_UNTOUCHED.add(key)
+            _TABLE_CACHE_STATS["gemm_batch_builds"] += 1
+            _TABLE_CACHE_STATS["gemm_builds"] += 1
+            if store is not None:
+                store.save("gemm", key, t)
 
 
 PREFETCH_TIMEOUT_ENV = "REPRO_DSE_BUILD_TIMEOUT"
@@ -790,46 +796,48 @@ def prefetch_conv_tables(hws: Sequence[HardwareSpec],
             if store is not None:
                 store.save("conv", key, table)
 
-    for attempt in range(retries + 1):
-        n = min(int(workers), len(missing))
-        pool = ProcessPoolExecutor(max_workers=n, mp_context=ctx)
-        futs: Dict[object, Tuple[tuple, HardwareSpec]] = {}
-        failed: List[Tuple[tuple, HardwareSpec]] = []
-        for key, hw in missing:
-            try:
-                futs[pool.submit(_build_conv_table,
-                                 (hw, layers, _fault_directive()))] = (key, hw)
-            except Exception:          # pool already broken mid-submission
-                failed.append((key, hw))
-        pending = dict(futs)
-        try:
-            for fut in as_completed(futs, timeout=timeout_s):
-                key, hw = pending.pop(fut)
+    with tracing.span("repro.dse.table_build", n=len(missing)):
+        for attempt in range(retries + 1):
+            n = min(int(workers), len(missing))
+            pool = ProcessPoolExecutor(max_workers=n, mp_context=ctx)
+            futs: Dict[object, Tuple[tuple, HardwareSpec]] = {}
+            failed: List[Tuple[tuple, HardwareSpec]] = []
+            for key, hw in missing:
                 try:
-                    seed(key, fut.result(timeout=0))
-                except Exception:      # worker exception or broken pool
+                    fut = pool.submit(_build_conv_table,
+                                      (hw, layers, _fault_directive()))
+                    futs[fut] = (key, hw)
+                except Exception:          # pool already broken mid-submission
                     failed.append((key, hw))
-        except FutTimeout:
-            pass
-        # Salvage: a timeout above abandons the iteration, but tasks that
-        # finished before the deadline still carry valid tables.
-        for fut, (key, hw) in pending.items():
-            if fut.done():
-                try:
-                    seed(key, fut.result(timeout=0))
-                    continue
-                except Exception:
-                    pass
-            else:
-                fut.cancel()
-            failed.append((key, hw))
-        _terminate_pool(pool)
-        missing = failed
-        if not missing:
-            return
-        time.sleep(PREFETCH_BACKOFF_S * (attempt + 1))
-    # retries exhausted: leave the remainder to the caller's guaranteed
-    # serial fallback (batch_build_conv_tables)
+            pending = dict(futs)
+            try:
+                for fut in as_completed(futs, timeout=timeout_s):
+                    key, hw = pending.pop(fut)
+                    try:
+                        seed(key, fut.result(timeout=0))
+                    except Exception:      # worker exception or broken pool
+                        failed.append((key, hw))
+            except FutTimeout:
+                pass
+            # Salvage: a timeout above abandons the iteration, but tasks that
+            # finished before the deadline still carry valid tables.
+            for fut, (key, hw) in pending.items():
+                if fut.done():
+                    try:
+                        seed(key, fut.result(timeout=0))
+                        continue
+                    except Exception:
+                        pass
+                else:
+                    fut.cancel()
+                failed.append((key, hw))
+            _terminate_pool(pool)
+            missing = failed
+            if not missing:
+                return
+            time.sleep(PREFETCH_BACKOFF_S * (attempt + 1))
+        # retries exhausted: leave the remainder to the caller's guaranteed
+        # serial fallback (batch_build_conv_tables)
 
 
 def table_cache_stats() -> Dict[str, object]:
@@ -1070,13 +1078,14 @@ def _pareto_mask(cycles: np.ndarray, energy: np.ndarray) -> np.ndarray:
     dominance: of several candidates with identical (cycles, energy) the
     first in input order is kept."""
     n = len(cycles)
-    order = np.lexsort((np.arange(n), energy, cycles))
-    keep = np.zeros(n, dtype=bool)
-    best_e = np.inf
-    for i in order:
-        if energy[i] < best_e:
-            keep[i] = True
-            best_e = energy[i]
+    with tracing.span("repro.dse.pareto_mask", n=n):
+        order = np.lexsort((np.arange(n), energy, cycles))
+        keep = np.zeros(n, dtype=bool)
+        best_e = np.inf
+        for i in order:
+            if energy[i] < best_e:
+                keep[i] = True
+                best_e = energy[i]
     return keep
 
 
@@ -1179,7 +1188,9 @@ class DSEResult:
         if self._energy_grids is None:
             if self._energy is None:
                 raise ValueError("result carries no energy tensors")
-            self._energy_grids = self._energy.grids(self.grid.costs)
+            with tracing.span("repro.dse.energy_grids",
+                              n=self.grid.n_candidates):
+                self._energy_grids = self._energy.grids(self.grid.costs)
         return self._energy_grids
 
     def energy_report(self, point: Optional[DSEPoint] = None
@@ -1249,7 +1260,10 @@ class DSEResult:
         optimum (infeasible candidates — score inf — never qualify)."""
         limit = self.best_score * (1 + frac)
         if self.grid is not None:
-            return self.grid.points_below(limit, self.grid_scores)
+            with tracing.span("repro.dse.frontier") as s:
+                pts = self.grid.points_below(limit, self.grid_scores)
+                s.n = len(pts)
+            return pts
         if self.archive is not None:
             if self.archive_scores is None:
                 return [p for p in self.archive if p.cycles <= limit]
@@ -1405,8 +1419,9 @@ class _GridEngine:
         bandwidth-independent half of the Sec. VI model.  Uncached tables
         are built up front: ``workers > 1`` fans scalar builds out across
         processes, and whatever remains is batch-built serially in one
-        vectorized pass per layer (``batch_build_conv_tables``) before
-        the per-triple loop walks the cache."""
+        vectorized pass per layer (``batch_build_conv_tables``); every
+        table is then fetched in one pass (the ``repro.dse.table_fetch``
+        span) before the per-triple loop."""
         bw_w = np.array([b[0] for b in b3s], dtype=float)
         bw_i = np.array([b[1] for b in b3s], dtype=float)
         bw_o = np.array([b[2] for b in b3s], dtype=float)
@@ -1430,11 +1445,12 @@ class _GridEngine:
             return mats, pmats, efields
         hws = [self.hw.replace(wbuf=wb * KB, ibuf=ib * KB, obuf=ob * KB)
                for wb, ib, ob in s3s]
-        if workers > 1:
-            prefetch_conv_tables(hws, self._conv_union, workers)
-        batch_build_conv_tables(hws, self._conv_union)
-        for si, hw in enumerate(hws):
-            table = get_conv_table(hw, self._conv_union)
+        with tracing.span("repro.dse.table_fetch", n=len(hws)):
+            if workers > 1:
+                prefetch_conv_tables(hws, self._conv_union, workers)
+            batch_build_conv_tables(hws, self._conv_union)
+            tables = [get_conv_table(hw, self._conv_union) for hw in hws]
+        for si, table in enumerate(tables):
             per_layer = table.layer_cycles_batch(bw_w, bw_i, bw_o)
             for name, cols in self.conv_cols.items():
                 if cols:
@@ -1472,13 +1488,15 @@ class _GridEngine:
         if not self._simd_union:
             # SIMD-free networks: zeroed contribution, no empty tables
             return mats, pmats, efields
-        # One vectorized derivation per layer covers every VMem candidate
-        # before the per-size loop (the table builds then hit the cache).
-        prefill_simd_tilings(self.hw, [vm * KB for vm in vmems],
-                             self._simd_union)
-        for vi, vm in enumerate(vmems):
-            table = get_simd_table(self.hw.replace(vmem=vm * KB),
-                                   self._simd_union)
+        with tracing.span("repro.dse.table_fetch", n=len(vmems)):
+            # One vectorized derivation per layer covers every VMem
+            # candidate before the table fetches (the builds then hit the
+            # tiling cache).
+            prefill_simd_tilings(self.hw, [vm * KB for vm in vmems],
+                                 self._simd_union)
+            tables = [get_simd_table(self.hw.replace(vmem=vm * KB),
+                                     self._simd_union) for vm in vmems]
+        for vi, table in enumerate(tables):
             row_stall = table.row_stall_batch(bw_v)
 
             def net_cycles(ids: List[int]) -> np.ndarray:
@@ -1531,9 +1549,10 @@ class _GridEngine:
             return mats, pmats, efields
         hws = [self.hw.replace(wbuf=wb * KB, ibuf=ib * KB, obuf=ob * KB)
                for wb, ib, ob in s3s]
-        batch_build_gemm_tables(hws, self._gemm_union)
-        for si, hw in enumerate(hws):
-            table = get_gemm_table(hw, self._gemm_union)
+        with tracing.span("repro.dse.table_fetch", n=len(hws)):
+            batch_build_gemm_tables(hws, self._gemm_union)
+            tables = [get_gemm_table(hw, self._gemm_union) for hw in hws]
+        for si, table in enumerate(tables):
             per_layer = table.layer_cycles_batch(bw_w, bw_i, bw_o)
             for name, cols in self.gemm_cols.items():
                 if cols:
@@ -1622,35 +1641,43 @@ def _grid_search_many(hw_base: HardwareSpec,
     ws, w_of = _project(bw_tuples, lambda t: t[3])
 
     eng = _GridEngine(hw_base, nets)
-    conv_mats, conv_pmats, conv_e = eng.conv_matrices(s3s, b3s,
-                                                      workers=workers)
-    simd_mats, simd_pmats, simd_e = eng.simd_matrices(vs, ws)
-    if eng._gemm_union:
-        # GEMMs share the conv separable axes (systolic-array buffers and
-        # bandwidths), so fold them into the conv-side structures before
-        # the grid composition — OUT-OF-PLACE: single-phase conv pmats
-        # alias their totals matrix, so the originals must not mutate.
-        # The phase dicts union disjoint "conv:*"/"gemm:*" keys and the
-        # energy fields add per key; everything downstream (gridax, the
-        # energy model, phase routing) is unchanged.
-        gemm_mats, gemm_pmats, gemm_e = eng.gemm_matrices(s3s, b3s)
-        conv_mats = {n: conv_mats[n] + gemm_mats[n] for n in conv_mats}
-        conv_pmats = {n: {**conv_pmats[n], **gemm_pmats[n]}
-                      for n in conv_pmats}
-        conv_e = {n: {k: v + gemm_e[n][k] for k, v in conv_e[n].items()}
-                  for n in conv_e}
+    with tracing.span("repro.dse.compose"):
+        conv_mats, conv_pmats, conv_e = eng.conv_matrices(s3s, b3s,
+                                                          workers=workers)
+        simd_mats, simd_pmats, simd_e = eng.simd_matrices(vs, ws)
+        if eng._gemm_union:
+            # GEMMs share the conv separable axes (systolic-array buffers
+            # and bandwidths), so fold them into the conv-side structures
+            # before the grid composition — OUT-OF-PLACE: single-phase
+            # conv pmats alias their totals matrix, so the originals must
+            # not mutate.  The phase dicts union disjoint "conv:*"/"gemm:*"
+            # keys and the energy fields add per key; everything
+            # downstream (gridax, the energy model, phase routing) is
+            # unchanged.
+            gemm_mats, gemm_pmats, gemm_e = eng.gemm_matrices(s3s, b3s)
+            conv_mats = {n: conv_mats[n] + gemm_mats[n] for n in conv_mats}
+            conv_pmats = {n: {**conv_pmats[n], **gemm_pmats[n]}
+                          for n in conv_pmats}
+            conv_e = {n: {k: v + gemm_e[n][k]
+                          for k, v in conv_e[n].items()}
+                      for n in conv_e}
     sizes_arr = np.array(size_tuples, dtype=np.int64)
 
     # On-device cycles sweeps reduce all networks in one vmapped dispatch
-    # (the candidate-space projections are shared).  Every float step —
-    # scoring, the frontier, Pareto — stays on the host for every backend
-    # (see ``repro.core.gridax``).
+    # (the candidate-space projections are shared), and each cost grid
+    # comes back to the host with its best and worst indices.  Every
+    # float step — scoring, the frontier, Pareto — stays on the host for
+    # every backend (see ``repro.core.gridax``).
+    n_cand = len(size_tuples) * len(bw_tuples)
     jax_cycles = None
     if gridax is not None and type(obj) is Cycles:
         names = list(nets)
-        jax_cycles = dict(zip(names, gridax.reduce_cycles_many(
-            [conv_mats[n] for n in names], [simd_mats[n] for n in names],
-            s3_of, b3_of, v_of, w_of, fused=(backend == "jax-fused"))))
+        with tracing.span("repro.dse.reduce", n=n_cand * len(names)):
+            reduced = gridax.reduce_cycles_many(
+                [conv_mats[n] for n in names], [simd_mats[n] for n in names],
+                s3_of, b3_of, v_of, w_of, fused=(backend == "jax-fused"))
+            jax_cycles = {n: (np.asarray(costs), bi, wi)
+                          for n, (costs, bi, wi) in zip(names, reduced)}
 
     out: Dict[str, DSEResult] = {}
     for name in nets:
@@ -1661,14 +1688,15 @@ def _grid_search_many(hw_base: HardwareSpec,
         scores = None
         if jax_cycles is not None:
             costs, bi, wi = jax_cycles[name]
-            grid = DSEGrid(np.asarray(costs), size_tuples, bw_tuples)
+            grid = DSEGrid(costs, size_tuples, bw_tuples)
             best = grid.point(bi)
             worst = grid.point(wi)
         else:
             if gridax is not None:
-                costs = np.asarray(gridax.outer_add(
-                    conv_mats[name], simd_mats[name],
-                    s3_of, b3_of, v_of, w_of))
+                with tracing.span("repro.dse.reduce", n=n_cand):
+                    costs = np.asarray(gridax.outer_add(
+                        conv_mats[name], simd_mats[name],
+                        s3_of, b3_of, v_of, w_of))
             else:
                 costs = (conv_mats[name][np.ix_(s3_of, b3_of)]
                          + simd_mats[name][np.ix_(v_of, w_of)])

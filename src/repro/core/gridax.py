@@ -38,7 +38,9 @@ keeps every accessor (``points``, ``economic_min_*``, ``pareto`` …)
 identical by construction.
 
 Importing this module is how a process enters the device path, so it
-also places JAX's persistent compilation cache (``use_compile_cache``).
+also places JAX's persistent compilation cache (``use_compile_cache``)
+and records JAX's compiles as ``repro.jax.compile`` spans
+(``tracing.watch_jax_compiles``).
 """
 from __future__ import annotations
 
@@ -53,6 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.reduce import grid_minmax_pallas
+from . import tracing
 
 # <checkout>/.jax_cache: a fixed path (the cache is keyed on it), listed
 # in .gitignore
@@ -70,6 +73,7 @@ def use_compile_cache() -> None:
 
 
 use_compile_cache()
+tracing.watch_jax_compiles()
 
 
 def _x64(fn):

@@ -41,7 +41,7 @@ from dataclasses import dataclass
 from pathlib import Path
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
-from . import faultinject
+from . import faultinject, tracing
 from .backward import expand_training_graph
 from .dse import (BWS, SEARCH_METHODS, SIZES_KB, DSEPoint, DSEResult, Layer,
                   clear_table_caches, resolve_backend, table_cache_stats)
@@ -171,7 +171,14 @@ class Workload:
         CNNs follow ``simulate``'s conventions: BN layers appear only in
         training graphs (inference graphs are BN-folded).  Names not in
         the CNN registry resolve as LLM configs and lower to a GEMM +
-        SIMD graph (``repro.models.frontends.lower_llm``)."""
+        SIMD graph (``repro.models.frontends.lower_llm``).  One call is
+        one ``repro.study.layers`` span, ``n`` = layers."""
+        with tracing.span("repro.study.layers") as s:
+            layers = self._expand()
+            s.n = len(layers)
+        return layers
+
+    def _expand(self) -> List[Layer]:
         if isinstance(self.net, str):
             from .networks import NETWORKS
             if self.net in NETWORKS:
@@ -357,7 +364,14 @@ class Study:
                     refine=None) -> Dict[str, DSEResult]:
         """Search several workloads at once, sharing the union-of-shapes
         cost tables (a Table IX style sweep builds each table once).
-        Returns ``{key: DSEResult}`` scored in ``objective``."""
+        Returns ``{key: DSEResult}`` scored in ``objective``.  One call is
+        one ``repro.study.search`` span, ``n`` = workloads."""
+        with tracing.span("repro.study.search", n=len(workloads)):
+            return self._search_many(workloads, size_budget_kb, bw_budget,
+                                     objective, method, refine)
+
+    def _search_many(self, workloads, size_budget_kb, bw_budget, objective,
+                     method, refine) -> Dict[str, DSEResult]:
         obj = resolve_objective(objective)
         nets = {key: as_workload(w).layers()
                 for key, w in workloads.items()}

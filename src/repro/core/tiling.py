@@ -48,6 +48,7 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
+from . import tracing
 from .hardware import HardwareSpec
 from .layers import ConvLayer, GemmLayer, SimdLayer
 
@@ -1039,11 +1040,26 @@ def simd_tilings_for_vmems(hw: HardwareSpec, vmems: Sequence[int],
 def prefill_simd_tilings(hw: HardwareSpec, vmems: Sequence[int],
                          layers: Sequence[SimdLayer]) -> None:
     """Batch-fill the SIMD tiling cache for every (vmem x unique layer
-    shape) pair not already present (byte sizes)."""
+    shape) pair not already present (byte sizes).  The derivation is
+    SIMD table construction: a ``repro.dse.table_build`` span, ``n`` =
+    pairs derived, covers it when any pair is missing; a pass that finds
+    every pair records nothing."""
+    base = _simd_hw_key(hw)
     seen = set()
+    todo: List[SimdLayer] = []
+    n_missing = 0
     for layer in layers:
         lk = _simd_layer_key(layer)
         if lk in seen:
             continue
         seen.add(lk)
-        simd_tilings_for_vmems(hw, vmems, layer)
+        missing = sum(((int(v),) + base[1:], lk) not in _SIMD_TILING_CACHE
+                      for v in vmems)
+        if missing:
+            todo.append(layer)
+            n_missing += missing
+    if not todo:
+        return
+    with tracing.span("repro.dse.table_build", n=n_missing):
+        for layer in todo:
+            simd_tilings_for_vmems(hw, vmems, layer)

@@ -153,6 +153,8 @@ class ServiceMetrics:
             self._counts["priced_requests"] += n_priced
 
     def completed(self, latency_s: float) -> None:
+        """One request answered; ``latency_s`` is the duration of its
+        ``repro.serve.request`` span (submit until the future is set)."""
         with self._lock:
             self._counts["completed"] += 1
             self._latencies.append(latency_s)

@@ -48,6 +48,15 @@ Architecture::
     depth, batch occupancy, coalescing ratio, p50/p95 request latency,
     and a race-safe cut of ``table_cache_stats()`` (cache/store hit
     rates).
+  * **Spans** (``core.tracing``).  ``submit`` gives each queued request
+    an id (``Ticket.rid``; a deduplicated submission shares its
+    primary's) and opens its ``repro.serve.request`` span, which ends
+    when its future is set; that span's duration is the latency
+    ``ServiceStats`` reports.  The dispatcher records
+    ``repro.serve.coalesce`` (first request dequeued until the window
+    closes or the batch is full, with the batch's ids); validation and
+    pricing run under ``tracing.requests``, so the spans they open carry
+    the ids of their requests, on the watchdog thread too.
 
 Thread-safety note: the dispatcher and its pricing watchdog threads
 drive the process-lifetime table caches concurrently with any direct
@@ -57,6 +66,7 @@ queries build each table exactly once.
 """
 from __future__ import annotations
 
+import contextvars
 import queue
 import threading
 import time
@@ -64,7 +74,7 @@ from concurrent.futures import Future
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Union
 
-from ..core import faultinject
+from ..core import faultinject, tracing
 from ..core.dse import DSEResult, table_cache_stats
 from ..core.study import Study, SweepRequest
 from .metrics import ServiceMetrics, ServiceStats
@@ -130,13 +140,15 @@ class Ticket:
     ``result(timeout=None)`` blocks for the ``DSEResult``; it raises the
     structured ``ServiceError`` subclass the service resolved the
     request with on failure.  Deduplicated submissions hold tickets
-    backed by the same future, so they observe one shared result."""
+    backed by the same future, so they observe one shared result.
+    ``rid`` is the request id its spans carry (a deduplicated
+    submission's is its primary's)."""
 
     def __init__(self, request: DSERequest, future: "Future[DSEResult]",
-                 submitted_at: float):
+                 rid: int):
         self.request = request
+        self.rid = rid
         self._future = future
-        self._submitted_at = submitted_at
 
     def done(self) -> bool:
         return self._future.done()
@@ -148,24 +160,24 @@ class Ticket:
                   ) -> Optional[BaseException]:
         return self._future.exception(timeout)
 
-    @property
-    def latency_s(self) -> Optional[float]:
-        """Submission-to-now wall time while pending, frozen usage is up
-        to the caller; ``None`` before submission bookkeeping."""
-        return time.monotonic() - self._submitted_at
-
 
 class _Entry:
-    """Internal queue record: request + future + deadline."""
-    __slots__ = ("request", "future", "submitted_at", "deadline", "key")
+    """Internal queue record: request + future + deadline, and the
+    request's open ``repro.serve.request`` span (its ``t0`` is the
+    submit time, on ``time.perf_counter`` like the deadline)."""
+    __slots__ = ("request", "future", "span", "deadline", "key")
 
-    def __init__(self, request: DSERequest, submitted_at: float,
+    def __init__(self, request: DSERequest, span: tracing.Span,
                  deadline: Optional[float], key: Optional[tuple]):
         self.request = request
         self.future: "Future[DSEResult]" = Future()
-        self.submitted_at = submitted_at
+        self.span = span
         self.deadline = deadline
         self.key = key
+
+    @property
+    def rid(self) -> int:
+        return self.span.rid[0]
 
     def remaining(self, now: float) -> Optional[float]:
         return None if self.deadline is None else self.deadline - now
@@ -181,7 +193,9 @@ def _run_with_watchdog(fn, timeout_s: Optional[float]):
     inline, unguarded).  A timed-out call keeps running on its daemon
     thread — it may still warm the shared caches — but its result is
     discarded and it can never touch a request future (completion
-    happens in the caller, after this returns)."""
+    happens in the caller, after this returns).  ``fn`` runs in a copy
+    of the caller's context, so its spans keep their parent and request
+    ids."""
     if timeout_s is None:
         return fn()
     box: Dict[str, object] = {}
@@ -195,8 +209,8 @@ def _run_with_watchdog(fn, timeout_s: Optional[float]):
         finally:
             done.set()
 
-    t = threading.Thread(target=run, daemon=True,
-                         name="repro-dse-pricing")
+    t = threading.Thread(target=contextvars.copy_context().run,
+                         args=(run,), daemon=True, name="repro-dse-pricing")
     t.start()
     if not done.wait(max(0.001, timeout_s)):
         raise _WatchdogTimeout(f"pricing exceeded {timeout_s:.3f}s")
@@ -322,7 +336,8 @@ class DSEService:
             req = DSERequest(request, size_budget_kb, bw_budget,
                              objective=objective, method=method,
                              timeout_s=timeout_s, tag=tag)
-        now = time.monotonic()
+        span = tracing.begin("repro.serve.request")
+        now = span.t0
         try:
             key: Optional[tuple] = req.dedup_key
             hash(key)
@@ -337,7 +352,7 @@ class DSEService:
                 if primary is not None:
                     self.metrics.count("submitted")
                     self.metrics.count("dedup_hits")
-                    return Ticket(req, primary.future, now)
+                    return Ticket(req, primary.future, primary.rid)
             if self._pending >= self.max_pending:
                 self.metrics.count("rejected")
                 raise AdmissionError(
@@ -345,14 +360,15 @@ class DSEService:
                     req)
             timeout = req.timeout_s if req.timeout_s is not None \
                 else self.default_timeout_s
-            entry = _Entry(req, now,
+            span.rid = (tracing.new_request_id(),)
+            entry = _Entry(req, span,
                            None if timeout is None else now + timeout, key)
             if key is not None:
                 self._inflight[key] = entry
             self._pending += 1
         self._queue.put(entry)
         self.metrics.count("submitted")
-        return Ticket(req, entry.future, now)
+        return Ticket(req, entry.future, entry.rid)
 
     # ---- metrics -----------------------------------------------------------
 
@@ -377,19 +393,21 @@ class DSEService:
                     return
                 continue
             batch = [first]
-            window_end = time.monotonic() + self.coalesce_window_s
-            while len(batch) < self.max_batch:
-                remaining = window_end - time.monotonic()
-                try:
-                    batch.append(self._queue.get(
-                        timeout=max(0.0, remaining)))
-                except queue.Empty:
-                    break
+            with tracing.span("repro.serve.coalesce") as s:
+                window_end = s.t0 + self.coalesce_window_s
+                while len(batch) < self.max_batch:
+                    remaining = window_end - time.perf_counter()
+                    try:
+                        batch.append(self._queue.get(
+                            timeout=max(0.0, remaining)))
+                    except queue.Empty:
+                        break
+                s.rid, s.n = tuple(e.rid for e in batch), len(batch)
             self._dispatch(batch)
 
     def _dispatch(self, batch: List[_Entry]) -> None:
         self.metrics.batch(len(batch))
-        now = time.monotonic()
+        now = time.perf_counter()
         live: List[_Entry] = []
         with self._lock:
             abandon = self._abandon
@@ -401,14 +419,15 @@ class DSEService:
             rem = e.remaining(now)
             if rem is not None and rem <= 0:
                 self._fail(e, RequestTimeout(
-                    f"deadline passed after {now - e.submitted_at:.3f}s "
+                    f"deadline passed after {now - e.span.t0:.3f}s "
                     f"in queue", e.request))
                 continue
             # Pre-validation: a workload that cannot even resolve to a
             # layer graph (unknown net, seq on a CNN, ...) fails alone
             # here instead of poisoning its group's shared search call.
             try:
-                e.request.workload.layers()
+                with tracing.requests(e.rid):
+                    e.request.workload.layers()
             except Exception as exc:
                 err = InvalidRequest(str(exc), e.request)
                 err.__cause__ = exc
@@ -451,8 +470,10 @@ class DSEService:
             return self.study.search_requests(requests)
 
         try:
-            results = _run_with_watchdog(
-                work, self._effective_timeout(entries, time.monotonic()))
+            with tracing.requests(tuple(e.rid for e in entries)):
+                results = _run_with_watchdog(
+                    work, self._effective_timeout(entries,
+                                                  time.perf_counter()))
         except Exception:
             self.metrics.count("degraded_batches")
             self._price_serial(entries)
@@ -465,7 +486,7 @@ class DSEService:
         """Degraded mode: each request priced (and watchdogged) alone, so
         failures and timeouts stay request-local."""
         for e in entries:
-            now = time.monotonic()
+            now = time.perf_counter()
             rem = e.remaining(now)
             if rem is not None and rem <= 0:
                 self._fail(e, RequestTimeout(
@@ -480,8 +501,9 @@ class DSEService:
                 return self.study.search_requests([req])[0]
 
             try:
-                res = _run_with_watchdog(
-                    work_one, self._effective_timeout([e], now))
+                with tracing.requests(e.rid):
+                    res = _run_with_watchdog(
+                        work_one, self._effective_timeout([e], now))
             except _WatchdogTimeout as exc:
                 self._fail(e, RequestTimeout(str(exc), e.request))
             except Exception as exc:
@@ -503,10 +525,12 @@ class DSEService:
 
     def _complete(self, e: _Entry, result: DSEResult) -> None:
         self._retire(e)
+        latency_s = e.span.end()
         e.future.set_result(result)
-        self.metrics.completed(time.monotonic() - e.submitted_at)
+        self.metrics.completed(latency_s)
 
     def _fail(self, e: _Entry, error: ServiceError) -> None:
         self._retire(e)
+        e.span.end()
         e.future.set_exception(error)
         self.metrics.failed(timeout=isinstance(error, RequestTimeout))
