@@ -12,7 +12,8 @@ A mix (``bench/traffic/<name>.json``) states its parameters only:
                    drawn from the seed) kept for the comparison, at most
                    ``max``
 
-Every query prices the configuration's own network at its own batch.
+Every query prices the configuration's own network at its own batch and
+sequence length (``bench/harness.py`` ``workload_of``).
 """
 from __future__ import annotations
 

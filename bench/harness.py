@@ -17,6 +17,7 @@ side, from submit until both are in hand.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import gc
 import importlib.util
 import json
@@ -36,6 +37,7 @@ from bench import trace as trace_mod
 ROOT = Path(__file__).resolve().parent.parent
 PLATFORM = "tpu"
 POSITIONS = 100_000          # sequence positions the check sample draws from
+NETWORK_KEYS = ("net", "training", "batch", "seq")   # keys of `network`
 
 
 class NoChip(RuntimeError):
@@ -97,13 +99,44 @@ def cell_metrics(bench: dict, cell: str, traced: bool) -> List[dict]:
     return [m for m in group if "workloads" not in m or cell in m["workloads"]]
 
 
-def reader(name: str, root: Path = ROOT) -> Callable[[Run], Optional[float]]:
+def metric_module(name: str, root: Path = ROOT):
     path = root / "bench" / "metrics" / f"{name}.py"
     spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
                                                   path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(name: str, root: Path = ROOT) -> Callable[[Run], Optional[float]]:
+    return metric_module(name, root).read
+
+
+def calls_of(bench: dict, cell: str, root: Path = ROOT
+             ) -> List[Tuple[str, str]]:
+    """The program functions, as (module, attribute path), whose calls
+    the cell's per-layer readers name in their ``CALLS``: the recorder
+    wraps them in every run, traced or not, so both do the same work."""
+    return sorted({tuple(c) for m in cell_metrics(bench, cell, True)
+                   for c in getattr(metric_module(m["name"], root),
+                                    "CALLS", ())})
+
+
+def workload_of(cfg: dict, name: Optional[str] = None):
+    """The configuration's ``network`` entry as the program's ``Workload``:
+    ``net``, ``training``, ``batch`` and, for an LLM configuration, an
+    optional ``seq``.  Any other key raises: a misspelt key is never
+    priced at the program's default."""
+    net = cfg["network"]
+    unknown = sorted(set(net) - set(NETWORK_KEYS))
+    if unknown:
+        raise ValueError(f"{cfg.get('name')}: unknown network key(s) "
+                         f"{unknown}; known: {list(NETWORK_KEYS)}")
+    from repro.core.study import Workload
+    seq = net.get("seq")
+    return Workload(net["net"], training=bool(net["training"]),
+                    batch=int(net["batch"]), name=name,
+                    seq=None if seq is None else int(seq))
 
 
 def peaks_for(kind: str, root: Path = ROOT) -> dict:
@@ -152,21 +185,20 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool, *,
     from repro.core import gridax                  # noqa: F401 (device path)
     from repro.core.energy import EnergyModel
     from repro.core.hardware import HardwareSpec
-    from repro.core.study import Study, Workload
+    from repro.core.study import Study
     from repro.serve import DSEClient, DSERequest, DSEService, ServiceError
+
+    wl = workload_of(cfg)               # an unknown key raises here
+
+    def workload(name: Optional[str] = None):
+        return dataclasses.replace(wl, name=name)
 
     # the spans are kept in every run (their cost is a few clock reads a
     # query); the per-layer metrics read them in traced runs only
-    recorder = spans.Recorder()         # raises on a missing program name
+    recorder = spans.Recorder(        # raises on a missing program name
+        calls=calls_of(bench, cell_name, root))
     recorder.install()
     compiles = _compile_counter()
-    net = cfg["network"]
-    batch = int(net["batch"])
-
-    def workload(name: Optional[str] = None):
-        return Workload(net["net"], training=bool(net["training"]),
-                        batch=batch, name=name)
-
     lat = cfg["lattice"]
     study = Study(HardwareSpec(**cfg["hardware"]), sizes=lat["sizes_kb"],
                   bws=lat["bws"], tol=lat["tol"],
@@ -202,7 +234,7 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool, *,
                     q = next(queries)
                 if time.perf_counter() >= deadline:
                     return
-                key = (q.size_budget_kb, q.bw_budget, q.objective, batch)
+                key = (q.size_budget_kb, q.bw_budget, q.objective, wl.batch)
                 req = DSERequest(workload(), q.size_budget_kb,
                                  q.bw_budget, objective=q.objective)
                 rec = Record(time.perf_counter())
@@ -280,7 +312,10 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool, *,
         device["window_s"] = summary.window_s
         result["breakdown"] = {
             "device_ops": [[n, s] for n, s in summary.ops],
-            "idle_gaps": [[n, s] for n, s in summary.idle_gaps]}
+            "idle_gaps": [[n, s] for n, s in summary.idle_gaps],
+            "device_by_span": sorted(
+                ([n, s] for n, s in summary.op_s_by_span.items()),
+                key=lambda x: -x[1])[:10]}
     metrics = {}
     for m in cell_metrics(bench, cell_name, traced):
         v = reader(m["name"], root)(r)

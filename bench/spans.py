@@ -49,6 +49,12 @@ def resolve(module: str, path: str):
     return owner, name, getattr(owner, name)
 
 
+def _operands(args) -> Tuple[Tuple[Tuple[int, ...], int], ...]:
+    """(shape, bytes per element) of each array argument, in order."""
+    return tuple((tuple(a.shape), a.dtype.itemsize) for a in args
+                 if hasattr(a, "shape") and hasattr(a, "dtype"))
+
+
 def _candidates(out) -> int:
     """Candidates in a device call's result: one grid, or a list of
     (grid, best, worst) per network."""
@@ -59,23 +65,31 @@ def _candidates(out) -> int:
 
 class Recorder:
     """Per-kind lists of (thread id, start, end) on ``time.perf_counter``,
-    the ``search`` span of every request (by ``id``), and the candidates
-    each outermost device call returned."""
+    the ``search`` span of every request (by ``id``), the candidates
+    each outermost device call returned, and, for each (module,
+    attribute path) in ``calls`` (which metric readers name in their
+    ``CALLS``), the (start, end, operand shapes) of every call."""
 
-    def __init__(self, targets=TARGETS):
+    def __init__(self, targets=TARGETS, calls=()):
         self.spans: Dict[str, List[Tuple[int, float, float]]] = {}
         self.search_of: Dict[int, Tuple[float, float]] = {}
         self.device_calls: List[Tuple[float, float, int]] = []
+        self.calls: Dict[Tuple[str, str], List[Tuple[float, float, tuple]]] \
+            = {c: [] for c in calls}
         self.annotate = False
         self._depth = threading.local()
         self._saved: List[tuple] = []
         self._resolved = [resolve(m, p) + (kind,)
                           for m, p, kind in targets]
+        self._called = [resolve(*c) + (c,) for c in calls]
 
     def install(self) -> None:
         for owner, name, fn, kind in self._resolved:
             self._saved.append((owner, name, fn))
             setattr(owner, name, self._wrap(fn, kind))
+        for owner, name, fn, c in self._called:
+            self._saved.append((owner, name, fn))
+            setattr(owner, name, self._wrap_call(fn, self.calls[c]))
 
     def uninstall(self) -> None:
         while self._saved:
@@ -110,6 +124,17 @@ class Recorder:
                     rec.search_of[id(r)] = (t0, t1)
             elif kind == "device" and depth == 0:
                 rec.device_calls.append((t0, t1, _candidates(out)))
+            return out
+        return wrapper
+
+    @staticmethod
+    def _wrap_call(fn, calls: list):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            t0 = time.perf_counter()
+            out = fn(*args, **kwargs)
+            calls.append((t0, time.perf_counter(),
+                          _operands([*args, *kwargs.values()])))
             return out
         return wrapper
 

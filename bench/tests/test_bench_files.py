@@ -53,7 +53,12 @@ def test_contract_limits():
             assert m["moves"] in {x["name"] for x in
                                   harness.cell_metrics(BENCH, cell, False)}
     for c in BENCH["configs"]:
-        assert (ROOT / c["file"]).is_file() and c["reduced"] == []
+        assert (ROOT / c["file"]).is_file()
+        # a configuration states its cuts: keys of its own file
+        cfg = json.loads((ROOT / c["file"]).read_text())
+        assert isinstance(c["reduced"], list) and len(c["reduced"]) <= 16
+        assert all(isinstance(k, str) and NAME.match(k) and k in cfg
+                   for k in c["reduced"]), c["reduced"]
         assert any(w["config"] == c["name"] for w in BENCH["workloads"])
 
 
