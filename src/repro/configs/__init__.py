@@ -30,6 +30,8 @@ _MODULES = {
     "llama4-maverick-400b-a17b": "llama4_maverick",
     "granite-moe-1b-a400m": "granite_moe_1b",
     "recurrentgemma-9b": "recurrentgemma_9b",
+    # priced only: the runtime model code has no latent attention
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

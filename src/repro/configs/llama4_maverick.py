@@ -11,7 +11,7 @@ def get_config() -> ModelConfig:
         name="llama4-maverick-400b-a17b", family="moe",
         n_layers=48, d_model=5120, n_heads=40, n_kv_heads=8, d_ff=8192,
         vocab_size=202048, head_dim=128, rope_theta=5e5,
-        n_experts=128, top_k=1, shared_expert=True,
+        n_experts=128, top_k=1, n_shared_experts=1,
         block_pattern=("attn+moe", "attn"), moe_every=2,
         tie_embeddings=False,
     )

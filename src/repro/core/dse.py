@@ -1689,7 +1689,9 @@ class _GridEngine:
         the caller outer-adds them into the conv matrices before the grid
         composition.  Same (totals, per-phase, energy fields) contract;
         tables are batch-built serially in one vectorized pass per layer
-        (``batch_build_gemm_tables``)."""
+        (``batch_build_gemm_tables``).  The fetch and the per-triple loop
+        are one ``repro.dse.gemm_compose`` span, ``n`` = size triples x
+        GEMM union columns."""
         bw_w = np.array([b[0] for b in b3s], dtype=float)
         bw_i = np.array([b[1] for b in b3s], dtype=float)
         bw_o = np.array([b[2] for b in b3s], dtype=float)
@@ -1708,25 +1710,28 @@ class _GridEngine:
             return mats, pmats, efields
         hws = [self.hw.replace(wbuf=wb * KB, ibuf=ib * KB, obuf=ob * KB)
                for wb, ib, ob in s3s]
-        with tracing.span("repro.dse.table_fetch", n=len(hws)):
-            batch_build_gemm_tables(hws, self._gemm_union)
-            tables = [get_gemm_table(hw, self._gemm_union) for hw in hws]
-        for si, table in enumerate(tables):
-            per_layer = table.layer_cycles_batch(bw_w, bw_i, bw_o)
-            for name, cols in self.gemm_cols.items():
-                if cols:
-                    mats[name][si] = per_layer[:, cols].sum(axis=1) \
-                        .astype(np.int64)
-                    e = efields[name]
-                    e["busy"][si] = table.busy[cols].sum()
-                    e["dram"][si] = table.dram[cols].sum()
-                    for buf in ("wbuf", "ibuf", "obuf", "bbuf"):
-                        e[buf][si] = table.sram[buf][cols].sum()
-                pcs = self.gemm_phase_cols[name]
-                if len(pcs) > 1:
-                    for ph, pc in pcs.items():
-                        pmats[name][ph][si] = per_layer[:, pc].sum(axis=1) \
+        with tracing.span("repro.dse.gemm_compose",
+                          n=len(s3s) * len(self._gemm_union)):
+            with tracing.span("repro.dse.table_fetch", n=len(hws)):
+                batch_build_gemm_tables(hws, self._gemm_union)
+                tables = [get_gemm_table(hw, self._gemm_union)
+                          for hw in hws]
+            for si, table in enumerate(tables):
+                per_layer = table.layer_cycles_batch(bw_w, bw_i, bw_o)
+                for name, cols in self.gemm_cols.items():
+                    if cols:
+                        mats[name][si] = per_layer[:, cols].sum(axis=1) \
                             .astype(np.int64)
+                        e = efields[name]
+                        e["busy"][si] = table.busy[cols].sum()
+                        e["dram"][si] = table.dram[cols].sum()
+                        for buf in ("wbuf", "ibuf", "obuf", "bbuf"):
+                            e[buf][si] = table.sram[buf][cols].sum()
+                    pcs = self.gemm_phase_cols[name]
+                    if len(pcs) > 1:
+                        for ph, pc in pcs.items():
+                            pmats[name][ph][si] = \
+                                per_layer[:, pc].sum(axis=1).astype(np.int64)
         return mats, pmats, efields
 
 

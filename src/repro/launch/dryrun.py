@@ -82,7 +82,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     defs = model.param_defs()
     moe_frac = 1.0
     if cfg.n_experts:
-        moe_frac = (cfg.top_k + (1 if cfg.shared_expert else 0)) / cfg.n_experts
+        moe_frac = (cfg.top_k + cfg.n_shared_experts) / cfg.n_experts
     n_total, n_active = RL.count_params(defs, {"expert_frac": moe_frac})
 
     t0 = time.time()

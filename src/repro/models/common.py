@@ -38,6 +38,12 @@ class ModelConfig:
     d_ff: int
     vocab_size: int
     head_dim: int = 0              # 0 -> d_model // n_heads
+    # multi-head latent attention (DeepSeek-V2); kv_lora_rank 0 -> off
+    kv_lora_rank: int = 0          # width of the compressed kv latent
+    qk_nope_head_dim: int = 0      # per-head q/k width without rotary
+    qk_rope_head_dim: int = 0      # per-head q width with rotary; k's is
+                                   # one head shared by all heads
+    v_head_dim: int = 0
     # norms / activations
     norm_type: str = "rmsnorm"     # rmsnorm | layernorm
     act: str = "silu"              # silu | gelu
@@ -55,7 +61,9 @@ class ModelConfig:
     top_k: int = 0
     moe_every: int = 1             # MoE on layers where (i % moe_every)==moe_offset
     moe_offset: int = 0
-    shared_expert: bool = False
+    moe_d_ff: int = 0              # routed-expert width (0 -> d_ff)
+    n_shared_experts: int = 0      # shared experts, run as one MLP of
+                                   # n_shared_experts * expert width
     moe_block: int = 1024          # token block size for dispatch
     moe_capacity: float = 1.25     # expert capacity factor (tokens dropped
                                    # beyond cap — standard capacity MoE)
@@ -92,6 +100,10 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    @property
+    def expert_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
 
     @property
     def pattern(self) -> Tuple[str, ...]:

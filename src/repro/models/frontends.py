@@ -20,6 +20,14 @@ through the SIMD model exactly like the paper's non-conv ops.
 ``Workload(net="qwen3_0_6b")`` resolves through ``resolve_llm_config``,
 so every downstream feature (objectives, refine, Pareto, phase
 attribution, store, backends) prices LLM serving and training for free.
+
+Conventions of the lowering, for every family: attention prices the
+full S x S score and A·V GEMMs (no causal halving); the embedding lookup
+is not priced (a DRAM gather, no array work); MoE layers price balanced
+dispatch and no cost for the router's top-k selection or the weighted
+combine of the experts' outputs.  Latent attention (``kv_lora_rank >
+0``, DeepSeek-V2 Sec. 2.1) is priced in its training form, without
+absorbing ``kv_b`` into the q and o projections.
 """
 from __future__ import annotations
 
@@ -30,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core import layers as L
+from ..core import tracing
 from ..core.layers import GemmLayer, SimdLayer, gemm
 from .common import ModelConfig
 
@@ -134,6 +143,35 @@ def _attention(cfg: ModelConfig, name: str, batch: int, s_q: int,
     return out
 
 
+def _latent_attention(cfg: ModelConfig, name: str, batch: int,
+                      seq: int) -> List[LlmLayer]:
+    """Multi-head latent attention (DeepSeek-V2 Sec. 2.1), training form:
+    q from a direct projection, one ``kv_a`` projection to the kv latent
+    plus the shared rotary key head, an RMSNorm over the latent, ``kv_b``
+    up to per-head nope keys and values, rotary on the rope parts (per
+    head for q, the one shared head for k), scores over nope + rope dims
+    and A·V over ``v_head_dim``, out projection, residual."""
+    D, H, t = cfg.d_model, cfg.n_heads, batch * seq
+    nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
+        cfg.v_head_dim
+    R = cfg.kv_lora_rank
+    return [
+        _norm(cfg, f"{name}.norm", t, D),
+        gemm(f"{name}.q", t, H * (nope + rope), D),
+        gemm(f"{name}.kv_a", t, R + rope, D),
+        L.rmsnorm(f"{name}.kv_norm", t, R),
+        gemm(f"{name}.kv_b", t, H * (nope + dv), R),
+        L.rotary(f"{name}.rope_q", t * H, rope),
+        L.rotary(f"{name}.rope_k", t, rope),
+        gemm(f"{name}.scores", seq, seq, nope + rope, count=batch * H,
+             param=False),
+        L.softmax(f"{name}.softmax", batch * H * seq, seq),
+        gemm(f"{name}.av", seq, dv, seq, count=batch * H, param=False),
+        gemm(f"{name}.o", t, D, H * dv),
+        _residual(f"{name}.res", t, D),
+    ]
+
+
 def _mlp(cfg: ModelConfig, name: str, tokens: int,
          gated: bool) -> List[LlmLayer]:
     D, F = cfg.d_model, cfg.d_ff
@@ -153,10 +191,12 @@ def _mlp(cfg: ModelConfig, name: str, tokens: int,
 
 def _moe(cfg: ModelConfig, name: str, tokens: int) -> List[LlmLayer]:
     """Router + capacity-balanced expert GEMMs: each of the ``n_experts``
-    identical expert MLPs processes ``ceil(tokens * top_k / n_experts)``
-    tokens (the balanced-dispatch expectation the capacity factor
-    enforces), expressed through ``GemmLayer.count``."""
-    D, F, E, K = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.top_k
+    identical expert MLPs (``expert_ff`` wide) processes
+    ``ceil(tokens * top_k / n_experts)`` tokens (the balanced-dispatch
+    expectation the capacity factor enforces), expressed through
+    ``GemmLayer.count``; the ``n_shared_experts`` run on every token as
+    one MLP of their summed width."""
+    D, F, E, K = cfg.d_model, cfg.expert_ff, cfg.n_experts, cfg.top_k
     m_exp = max(1, math.ceil(tokens * K / E))
     out: List[LlmLayer] = [
         _norm(cfg, f"{name}.norm", tokens, D),
@@ -167,12 +207,13 @@ def _moe(cfg: ModelConfig, name: str, tokens: int) -> List[LlmLayer]:
         L.activation(f"{name}.e_act", m_exp * E, F, cfg.act, gated=True),
         gemm(f"{name}.e_down", m_exp, D, F, count=E),
     ]
-    if cfg.shared_expert:
-        out += [gemm(f"{name}.s_gate", tokens, F, D),
-                gemm(f"{name}.s_up", tokens, F, D),
-                L.activation(f"{name}.s_act", tokens, F, cfg.act,
+    if cfg.n_shared_experts:
+        Fs = cfg.n_shared_experts * F
+        out += [gemm(f"{name}.s_gate", tokens, Fs, D),
+                gemm(f"{name}.s_up", tokens, Fs, D),
+                L.activation(f"{name}.s_act", tokens, Fs, cfg.act,
                              gated=True),
-                gemm(f"{name}.s_down", tokens, D, F)]
+                gemm(f"{name}.s_down", tokens, D, Fs)]
     out.append(_residual(f"{name}.res", tokens, D))
     return out
 
@@ -234,11 +275,18 @@ def lower_llm(cfg: ModelConfig, batch: int = 1,
     training workload.  Embedding lookups are not modeled (pure DRAM
     gathers, no array work); the lm-head projection is.  VLM patch
     stubs extend the token stream (early fusion); enc-dec configs emit
-    the encoder stack plus cross-attention in every decoder layer."""
+    the encoder stack plus cross-attention in every decoder layer.  One
+    call is one ``repro.models.lower`` span, ``n`` = layers emitted."""
     S = seq if seq is not None else LLM_SEQ_DEFAULT
     if S <= 0 or batch <= 0:
         raise ValueError(f"batch/seq must be positive, got {batch}/{S}")
-    B = batch
+    with tracing.span("repro.models.lower") as span:
+        out = _lower(cfg, batch, S)
+        span.n = len(out)
+    return out
+
+
+def _lower(cfg: ModelConfig, B: int, S: int) -> List[LlmLayer]:
     S = S + cfg.n_patches                   # early-fusion patch prefix
     D = cfg.d_model
     out: List[LlmLayer] = []
@@ -253,7 +301,9 @@ def lower_llm(cfg: ModelConfig, batch: int = 1,
     for i, kind in enumerate(kinds):
         blk = f"blk{i}"
         local = bool(pat) and pat[i % len(pat)] == "local"
-        if kind.startswith("attn"):
+        if kind.startswith("attn") and cfg.kv_lora_rank:
+            out += _latent_attention(cfg, f"{blk}.attn", B, S)
+        elif kind.startswith("attn"):
             out += _attention(cfg, f"{blk}.attn", B, S, S, local=local,
                               rope=cfg.rope_fraction > 0)
             if cfg.encoder_layers:

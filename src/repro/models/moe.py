@@ -23,7 +23,8 @@ from .layers import _act
 
 def moe_defs(cfg: ModelConfig, lead: Tuple[int, ...] = ()) -> Dict:
     la = ("layers",) * len(lead)
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    d, f, e = cfg.d_model, cfg.expert_ff, cfg.n_experts
+    fs = cfg.n_shared_experts * f
     # expert weights shard experts over the FSDP ('data') axis and the
     # expert FF dim over the tensor axis; the embed dim stays unsharded
     # (it cannot reuse 'data' — one mesh axis per spec position)
@@ -33,10 +34,10 @@ def moe_defs(cfg: ModelConfig, lead: Tuple[int, ...] = ()) -> Dict:
         "wg": ParamDef(lead + (e, d, f), la + ("experts", None, "expert_ff")),
         "wo": ParamDef(lead + (e, f, d), la + ("experts", "expert_ff", None)),
     }
-    if cfg.shared_expert:
-        out["shared_wi"] = ParamDef(lead + (d, f), la + ("embed", "ff"))
-        out["shared_wg"] = ParamDef(lead + (d, f), la + ("embed", "ff"))
-        out["shared_wo"] = ParamDef(lead + (f, d), la + ("ff", "embed"))
+    if fs:
+        out["shared_wi"] = ParamDef(lead + (d, fs), la + ("embed", "ff"))
+        out["shared_wg"] = ParamDef(lead + (d, fs), la + ("embed", "ff"))
+        out["shared_wo"] = ParamDef(lead + (fs, d), la + ("ff", "embed"))
     return out
 
 
@@ -119,7 +120,7 @@ def apply_moe(cfg: ModelConfig, p: Dict, x: jax.Array,
 
     ys, auxs = jax.lax.map(block_fn, tb)
     y = ys.reshape(-1, d)[:n].reshape(b, s, d)
-    if cfg.shared_expert:
+    if cfg.n_shared_experts:
         h = _act(cfg, x @ p["shared_wg"]) * (x @ p["shared_wi"])
         y = y + h @ p["shared_wo"]
     return y, auxs.mean()
