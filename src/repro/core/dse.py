@@ -90,6 +90,7 @@ import time
 from collections import OrderedDict
 from dataclasses import dataclass, field, replace
 from functools import lru_cache
+from math import isqrt
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
 import numpy as np
@@ -1086,19 +1087,54 @@ class _EnergyFields:
                           + simd["dram"][self.v_of]))
 
 
+def _walk(cycles: np.ndarray, energy: np.ndarray,
+          index: np.ndarray) -> np.ndarray:
+    """The exact frontier rule over the given points: in (cycles, energy,
+    index) order, keep a point whose energy is below the running minimum
+    of those before it (NaN read as +inf).  Returns the kept indices in
+    that order."""
+    order = np.lexsort((index, energy, cycles))
+    e = energy[order]
+    run = np.minimum.accumulate(np.where(np.isnan(e), np.inf, e))
+    before = np.concatenate(([np.inf], run[:-1]))
+    return index[order][e < before]
+
+
 def _pareto_mask(cycles: np.ndarray, energy: np.ndarray) -> np.ndarray:
-    """Boolean mask of the 2-D Pareto frontier (minimize both).  Weak
-    dominance: of several candidates with identical (cycles, energy) the
-    first in input order is kept."""
+    """Boolean mask of the 2-D Pareto frontier (minimize both): a point
+    is kept when its energy is below that of every point before it in
+    (cycles, energy, index) order.  Of equal (cycles, energy) pairs the
+    first in input order is kept; a NaN energy is never kept and never
+    lowers the minimum; an inf energy is never kept.
+
+    A staircase of block pivots (each block's cycle and energy argmins)
+    drops every point that one staircase point precedes with no higher
+    energy; the rule then runs on the survivors.  Exact: a dropped
+    point's chain of witnesses ends at a survivor before it with energy
+    no higher, so each survivor is kept just as over the whole input."""
     n = len(cycles)
-    with tracing.span("repro.dse.pareto_mask", n=n):
-        order = np.lexsort((np.arange(n), energy, cycles))
-        keep = np.zeros(n, dtype=bool)
-        best_e = np.inf
-        for i in order:
-            if energy[i] < best_e:
-                keep[i] = True
-                best_e = energy[i]
+    keep = np.zeros(n, dtype=bool)
+    with tracing.span("repro.dse.pareto_mask", n=n) as s:
+        index = np.arange(n)
+        b = max(isqrt(n), 1)
+        full = n // b * b
+        pivots = np.unique(np.concatenate((
+            cycles[:full].reshape(-1, b).argmin(axis=1) + index[:full:b],
+            energy[:full].reshape(-1, b).argmin(axis=1) + index[:full:b],
+            index[full:])))
+        stair = _walk(cycles[pivots], energy[pivots], pivots)
+        rest = index
+        if len(stair):
+            # the last staircase point at or below each point's cycles
+            # (the point itself where there is none); it witnesses for
+            # the point when it comes first with energy no higher
+            pos = np.searchsorted(cycles[stair], cycles, side="right") - 1
+            j = np.where(pos >= 0, stair[pos], index)
+            cj, ej = cycles[j], energy[j]
+            rest = np.flatnonzero(~((ej <= energy) & (
+                (cj < cycles) | (ej < energy) | (j < index))))
+        s.info = {"survivors": len(rest)}
+        keep[_walk(cycles[rest], energy[rest], rest)] = True
     return keep
 
 
